@@ -56,7 +56,11 @@ def main() -> None:
     only = {s.strip() for s in args.only.split(",") if s.strip()}
 
     import importlib
+    import os
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     t0 = time.time()
     failed: list[str] = []
     errors: dict[str, str] = {}

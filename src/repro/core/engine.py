@@ -70,25 +70,27 @@ def wire_ser_ps(nbytes, ch: "Channels", chan_clipped, extra_wire=None):
         ``replay_ppm`` on the same channel by the lowering contract.
     """
     bw = ch.bw_MBps[chan_clipped]
-    base = ser_ps(nbytes, bw)
     if ch.flit_size is None:
-        return base
+        return ser_ps(nbytes, bw)
     fsize = ch.flit_size[chan_clipped]
+    flit = fsize > 0
     fpay = jnp.maximum(ch.flit_payload[chan_clipped], 1)
     wire = ((nbytes + fpay - 1) // fpay) * fsize
     if extra_wire is not None:
         wire = wire + extra_wire
-    fser = ser_ps(wire, bw)
+    # one divide by the bandwidth for both kinds of channel: a variable
+    # int64 divide is the costliest op the TPU compiler builds here
+    ser = ser_ps(jnp.where(flit, wire, nbytes), bw)
     if ch.replay_ppm is not None:
         ppm = ch.replay_ppm[chan_clipped]
-        # floor(fser * (1e6 + ppm) / 1e6), decomposed so the product never
+        # floor(ser * (1e6 + ppm) / 1e6), decomposed so the product never
         # exceeds int64 even with ppm at the MAX_REPLAY_PPM clamp (1e9):
         # identical to the oracle's arbitrary-precision formula for any
-        # fser below ~9.2e15 ps
+        # ser below ~9.2e15 ps
         scale = 1_000_000 + ppm
-        q, r = fser // 1_000_000, fser % 1_000_000
-        fser = q * scale + (r * scale) // 1_000_000
-    return jnp.where(fsize > 0, fser, base)
+        q, r = ser // 1_000_000, ser % 1_000_000
+        ser = jnp.where(flit, q * scale + (r * scale) // 1_000_000, ser)
+    return ser
 
 
 class Channels(NamedTuple):
@@ -334,9 +336,22 @@ def round_bound(hops: Hops) -> int:
                                jw.reshape(-1, jw.shape[-1])))
 
 
-def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, with_stalls=False,
-               carry: StreamCarry | None = None, impl: str = "scan"):
-    """One sort→segmented-scan→propagate pass.  arrive: (N, H+1).
+@jax.jit
+def hop_ser_ps(hops: Hops, ch: Channels):
+    """(N, H) serialization time of every hop on its channel — a static
+    property of the item, computed once per simulation.  Jitted on its own:
+    its int64 divides are the costliest code the TPU compiler builds for
+    the engine, and every fixpoint program of one workload (each serve
+    implementation, each stream window of one shape) shares this one."""
+    clip = jnp.minimum(hops.channel, ch.bw_MBps.shape[0] - 1)
+    return wire_ser_ps(hops.nbytes, ch, clip, extra_wire=hops.extra_wire_bytes)
+
+
+def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, ser,
+               with_stalls=False, carry: StreamCarry | None = None,
+               impl: str = "scan"):
+    """One sort→segmented-scan→propagate pass.  arrive: (N, H+1); ser:
+    (N, H) `hop_ser_ps`.
 
     ``with_stalls=True`` (telemetry replay, `core.telemetry`) additionally
     returns the per-item retraining-stall share of the queueing wait —
@@ -361,9 +376,8 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, with_stalls=False,
     # push invalid items to a dummy tail segment so they never contend
     sort_chan = jnp.where(flat_valid, flat_chan, jnp.int32(ch.bw_MBps.shape[0]))
 
-    # lexsort by (channel, arrive, flat index): two stable passes
-    order = jnp.argsort(flat_arrive, stable=True)
-    order = order[jnp.argsort(sort_chan[order], stable=True)]
+    order = channel_time_order(sort_chan, flat_arrive,
+                               ch.bw_MBps.shape[0] + 1)
 
     chan_clipped = jnp.minimum(flat_chan[order], ch.bw_MBps.shape[0] - 1)
     s_chan = flat_chan[order]
@@ -372,9 +386,7 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, with_stalls=False,
     s_dir = hops.direction.reshape(k)[order]
     s_row = hops.row.reshape(k)[order]
     s_bytes = hops.nbytes.reshape(k)[order]
-    s_extra = (hops.extra_wire_bytes.reshape(k)[order]
-               if hops.extra_wire_bytes is not None else None)
-    s_ser = wire_ser_ps(s_bytes, ch, chan_clipped, extra_wire=s_extra)
+    s_ser = ser.reshape(k)[order]
     s_turn = ch.turnaround_ps[chan_clipped]
     s_rowhit = ch.row_hit_ps[chan_clipped]
     s_rowmiss = ch.row_miss_ps[chan_clipped]
@@ -383,29 +395,6 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, with_stalls=False,
     # compiles to the exact PR-1 scan
     has_retrain = hops.retrain_after_ps is not None
     has_carry = carry is not None
-    if impl != "scan":
-        # Pallas serve-round kernel (`kernels.serve_round`): one code path
-        # for every layout — deterministic/no-carry configs ride the carry
-        # semantics with cold seeds, bit-identical by the empty-carry
-        # equivalence the streaming suite property-tests
-        from ..kernels.serve_round.ops import serve_round
-
-        s_retrain = (hops.retrain_after_ps.reshape(k)[order]
-                     if has_retrain else jnp.zeros(k, jnp.int64))
-        if has_carry:
-            seed_ix = jnp.clip(s_chan, 0, ch.bw_MBps.shape[0] - 1)
-            sd = (carry.depart_ps[seed_ix], carry.last_dir[seed_ix],
-                  carry.last_row[seed_ix], carry.down_until_ps[seed_ix])
-        else:
-            sd = (jnp.zeros(k, jnp.int64), jnp.full(k, -1, jnp.int8),
-                  jnp.full(k, -2, jnp.int32), jnp.zeros(k, jnp.int64))
-        serving = s_valid & (s_bytes > 0)
-        marker = s_valid & (s_bytes == 0) & (s_retrain > 0)
-        s_start, s_depart, s_stall = serve_round(
-            s_chan, serving, marker, s_arrive, s_dir, s_row, s_ser,
-            s_turn, s_rowhit, s_rowmiss, s_retrain, *sd, impl=impl)
-        return _scatter_round(hops, issue_ps, order, s_start, s_depart,
-                              s_stall if with_stalls else None)
     xs = (s_chan, s_valid, s_arrive, s_dir, s_row, s_ser, s_turn, s_rowhit,
           s_rowmiss, s_bytes)
     if has_retrain:
@@ -532,12 +521,59 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, with_stalls=False,
             jnp.where(valid | marker, new_down, prev_down),)
         return new_carry, ys
 
-    init = (jnp.int32(-1), jnp.int64(0), jnp.int8(-1), jnp.int32(-2))
-    if has_retrain or has_carry:
-        init = init + (jnp.int64(0),)
-    _, out = jax.lax.scan(scan_fn, init, xs)
+    def lax_round():
+        init = (jnp.int32(-1), jnp.int64(0), jnp.int8(-1), jnp.int32(-2))
+        if has_retrain or has_carry:
+            init = init + (jnp.int64(0),)
+        return jax.lax.scan(scan_fn, init, xs)[1]
+
+    if impl == "scan":
+        out = lax_round()
+    else:
+        # Pallas serve-round kernel (`kernels.serve_round`): one code path
+        # for every layout — deterministic/no-carry configs ride the carry
+        # semantics with cold seeds, bit-identical by the empty-carry
+        # equivalence the streaming suite property-tests.  A round that
+        # breaks the kernel's int32 span contract is resolved on the int64
+        # lax path instead, so no over-span round returns a kernel schedule.
+        from ..kernels.serve_round.ops import serve_round
+
+        s_retrain = (hops.retrain_after_ps.reshape(k)[order]
+                     if has_retrain else jnp.zeros(k, jnp.int64))
+        if has_carry:
+            seed_ix = jnp.clip(s_chan, 0, ch.bw_MBps.shape[0] - 1)
+            sd = (carry.depart_ps[seed_ix], carry.last_dir[seed_ix],
+                  carry.last_row[seed_ix], carry.down_until_ps[seed_ix])
+        else:
+            sd = (jnp.zeros(k, jnp.int64), jnp.full(k, -1, jnp.int8),
+                  jnp.full(k, -2, jnp.int32), jnp.zeros(k, jnp.int64))
+        serving = s_valid & (s_bytes > 0)
+        marker = s_valid & (s_bytes == 0) & (s_retrain > 0)
+        *kout, ok = serve_round(
+            s_chan, serving, marker, s_arrive, s_dir, s_row, s_ser,
+            s_turn, s_rowhit, s_rowmiss, s_retrain, *sd, impl=impl)
+        kout = tuple(kout[:3 if with_stalls else 2])
+        out = jax.lax.cond(ok, lambda: kout, lax_round)
     return _scatter_round(hops, issue_ps, order, out[0], out[1],
                           out[2] if with_stalls else None)
+
+
+def channel_time_order(chan, time, n_chan: int):
+    """Permutation sorting items by ``(chan, time, flat index)``; ``chan``
+    takes values in ``[0, n_chan)``.
+
+    Two unstable sorts with unique keys instead of two stable argsorts: the
+    time pass takes the flat index as its second key, and the channel pass
+    sorts the packed key ``chan * K + time position`` (int32 wherever it
+    fits).  The order is the same; the TPU compiler builds a stable int64
+    argsort of a deployment-size round several times more slowly.
+    """
+    k = time.shape[0]
+    idx = jnp.arange(k, dtype=jnp.int32)
+    by_time = jax.lax.sort((time, idx), num_keys=2, is_stable=False)[1]
+    kdt = jnp.int32 if n_chan * k < 2 ** 31 else jnp.int64
+    key = chan[by_time].astype(kdt) * k + idx.astype(kdt)
+    return jax.lax.sort((key, by_time), num_keys=1, is_stable=False)[1]
 
 
 def _scatter_round(hops: Hops, issue_ps, order, s_start, s_depart, s_stall):
@@ -621,13 +657,15 @@ def simulate(hops: Hops, channels: Channels, issue_ps: jnp.ndarray,
     """
     opts = _merge_options("simulate", options, max_rounds=max_rounds)
     budget = opts.max_rounds if opts.max_rounds > 0 else round_bound(hops)
-    return _simulate_fixpoint(hops, channels, issue_ps, jnp.int64(budget),
+    return _simulate_fixpoint(hops, channels, issue_ps,
+                              hop_ser_ps(hops, channels), jnp.int64(budget),
                               carry, opts.kernel_impl)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
-def _simulate_fixpoint(hops: Hops, channels: Channels, issue_ps, rounds,
-                       carry: StreamCarry | None, impl: str) -> Schedule:
+def _simulate_fixpoint(hops: Hops, channels: Channels, issue_ps, ser0,
+                       rounds, carry: StreamCarry | None,
+                       impl: str) -> Schedule:
     n, h = hops.channel.shape
     has_join = hops.join_id is not None
     join_seed = carry.join_seed_ps if carry is not None else None
@@ -636,9 +674,6 @@ def _simulate_fixpoint(hops: Hops, channels: Channels, issue_ps, rounds,
     # included: it delays the item even uncontended; retraining stalls and
     # join gates only ever delay items, so they keep this a valid lower
     # bound)
-    ser0 = wire_ser_ps(hops.nbytes, channels,
-                       jnp.minimum(hops.channel, channels.bw_MBps.shape[0] - 1),
-                       extra_wire=hops.extra_wire_bytes)
     step = jnp.where(hops.valid, ser0 + hops.fixed_after_ps, 0)
     arrive0 = issue_ps[:, None] + jnp.concatenate(
         [jnp.zeros((n, 1), jnp.int64), jnp.cumsum(step, axis=1)], axis=1
@@ -653,7 +688,8 @@ def _simulate_fixpoint(hops: Hops, channels: Channels, issue_ps, rounds,
         eff_issue = (_join_gate(hops, issue_ps, arrive, join_seed)
                      if has_join else issue_ps)
         new_arrive, start, depart = _one_round(hops, channels, eff_issue,
-                                               arrive, carry=carry, impl=impl)
+                                               arrive, ser0, carry=carry,
+                                               impl=impl)
         resid = jnp.max(jnp.abs(new_arrive - arrive))
         return i + 1, new_arrive, start, depart, resid
 
@@ -686,8 +722,8 @@ def replay_round(hops: Hops, channels: Channels, sched: Schedule,
     is a fixpoint of the *seeded* round map, so the same argument applies.
     """
     _, start, depart, stall = _one_round(
-        hops, channels, sched.arrive[:, 0], sched.arrive, with_stalls=True,
-        carry=carry)
+        hops, channels, sched.arrive[:, 0], sched.arrive,
+        hop_ser_ps(hops, channels), with_stalls=True, carry=carry)
     return start, depart, stall
 
 

@@ -52,7 +52,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .engine import Channels, Hops, Schedule, replay_round, wire_ser_ps
+from .engine import (Channels, Hops, Schedule, channel_time_order,
+                     replay_round, wire_ser_ps)
 from .snoop_filter import SFEvents, owner_count
 
 # ---------------------------------------------------------------------------
@@ -212,18 +213,16 @@ def channel_telemetry(hops: Hops, channels: Channels, sched: Schedule,
     wire = per_chan(wire_item)
 
     # peak backlog: ±1 events (arrival +1, grant −1) lexsorted by
-    # (channel, time, arrivals-first); every channel's deltas sum to zero
-    # and segments are channel-contiguous, so the global running sum IS the
-    # per-channel backlog and a per-channel scatter-max reads the peak.
+    # (channel, time, arrivals-first — arrivals hold the lower event
+    # indices); every channel's deltas sum to zero and segments are
+    # channel-contiguous, so the global running sum IS the per-channel
+    # backlog and a per-channel scatter-max reads the peak.
     times = jnp.concatenate([sched.arrive[:, :h].reshape(k),
                              sched.start.reshape(k)])
     chans2 = jnp.concatenate([flat_c, flat_c])
     delta = jnp.concatenate([jnp.where(occupied, 1, 0),
                              jnp.where(occupied, -1, 0)]).astype(jnp.int64)
-    typ = jnp.concatenate([jnp.zeros(k, jnp.int32), jnp.ones(k, jnp.int32)])
-    order = jnp.argsort(typ, stable=True)
-    order = order[jnp.argsort(times[order], stable=True)]
-    order = order[jnp.argsort(chans2[order], stable=True)]
+    order = channel_time_order(chans2, times, c + 1)
     backlog = jnp.cumsum(delta[order])
     peak = jnp.zeros(c + 1, jnp.int64).at[chans2[order]].max(backlog)[:c]
 

@@ -23,10 +23,12 @@ per-channel table gathers and seed gathers already done).  The wrapper
      otherwise), and restores absolute times.
 
 Returns the engine's masked per-item ``(start, depart, retrain_stall)``
-triple in int64 picoseconds; non-serving items pass through at their
-arrival with zero stall, exactly like the lax scan path.  One round's time
-span must fit 2**29 after rebasing (documented kernel contract; holds by
-orders of magnitude at bench sizes).
+triple in int64 picoseconds — non-serving items pass through at their
+arrival with zero stall, exactly like the lax scan path — and ``ok``, a
+bool scalar that is False when the round breaks the kernel's int32 span
+contract (`kernel.SPAN_LIMIT`): an input over the limit before the int32
+cast, or a composed value that reached it.  A round that is not ``ok``
+carries no valid schedule; the engine resolves it on its int64 lax path.
 """
 
 from __future__ import annotations
@@ -36,10 +38,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import NEG, serve_scan
+from .kernel import NEG, SPAN_LIMIT, serve_scan
 from .ref import serve_scan_ref
-
-_SPAN_LIMIT = (1 << 29) - 1
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -84,12 +84,10 @@ def serve_round(chan, serving, marker, arrive, direction, row, ser, turn,
     # bind (every start is >= arrive >= base), so clamping preserves the
     # schedule bit-for-bit while keeping the rebase in range.
     base = jnp.min(arrive)
-    arr = (arrive - base).astype(jnp.int32)
-    sdep = (jnp.maximum(sd_dep, base - turn) - base).astype(jnp.int32)
-    sdwn = (jnp.maximum(sd_down, base) - base).astype(jnp.int32)
-    gap32 = gap.astype(jnp.int32)
-    s32 = s.astype(jnp.int32)
-    r32 = retrain.astype(jnp.int32)
+    rebased = (arrive - base, jnp.maximum(sd_dep, base - turn) - base,
+               jnp.maximum(sd_down, base) - base, gap, s, retrain)
+    in_hi = functools.reduce(jnp.maximum, [jnp.max(x) for x in rebased])
+    arr, sdep, sdwn, gap32, s32, r32 = (x.astype(jnp.int32) for x in rebased)
 
     neg = jnp.full(k, NEG, jnp.int32)
     zero = jnp.zeros(k, jnp.int32)
@@ -123,10 +121,14 @@ def serve_round(chan, serving, marker, arrive, direction, row, ser, turn,
     if use == "auto":
         use = "pallas" if jax.default_backend() == "tpu" else "ref"
     if use == "ref":
+        # the sequential scan stores every state it reaches, so the first
+        # over-limit depart is exact and shows in the outputs
         d32 = serve_scan_ref(m00, m01, m10, m11, c0, c1)
+        hi = jnp.max(d32)
     else:
-        d32 = serve_scan(m00, m01, m10, m11, c0, c1,
-                         interpret=(use == "interpret"))
+        d32, hi = serve_scan(m00, m01, m10, m11, c0, c1,
+                             interpret=(use == "interpret"))
+    ok = (in_hi <= SPAN_LIMIT) & (hi <= SPAN_LIMIT)
     d = d32.astype(jnp.int64) + base
 
     # stall = grant delay the down-until clock added on top of contention
@@ -137,4 +139,4 @@ def serve_round(chan, serving, marker, arrive, direction, row, ser, turn,
     out_depart = jnp.where(serving, d, arrive)
     out_stall = jnp.where(
         serving, start - jnp.maximum(arrive, eff_dep + gap), jnp.int64(0))
-    return out_start, out_depart, out_stall
+    return out_start, out_depart, out_stall, ok

@@ -35,14 +35,14 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.configs import (ARCH_IDS, SHAPES, get_config,  # noqa: E402
                            shape_applicable)
-from repro.jax_compat import set_mesh, tree_as_shardings  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import model_zoo as zoo  # noqa: E402
 from repro.models import transformer as TF  # noqa: E402
 from repro.models.layers import DTYPE  # noqa: E402
 from repro.optim import adamw  # noqa: E402
 from repro.parallel.sharding import (ShardingRules, logical,  # noqa: E402
-                                     param_specs, prune_tree_specs, use_rules)
+                                     param_specs, prune_tree_specs,
+                                     tree_as_shardings, use_rules)
 
 COLLECTIVE_RE = re.compile(
     r"(\w[\w.]*)\s*=\s*([a-z0-9]+)\[([\d,]*)\][^=]*?"
@@ -101,7 +101,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     kind = shape.kind
 
     t0 = time.time()
-    with set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         aparams = zoo.abstract_params(cfg)
         pspecs = prune_tree_specs(param_specs(TF.param_axes(cfg)), aparams,
                                   mesh)

@@ -5,30 +5,23 @@ touches jax device state.  Single pod: 16x16 = 256 chips (data, model);
 multi-pod: 2x16x16 = 512 chips (pod, data, model).  The dry-run
 (launch/dryrun.py) sets XLA_FLAGS for 512 host placeholder devices *before*
 importing jax; everything else sees the real device count.
-
-``AxisType`` / ``make_mesh`` come from `repro.jax_compat`: on jax 0.4.x
-(which has neither ``jax.sharding.AxisType`` nor the ``axis_types`` kwarg)
-they degrade to untyped meshes, which is semantically what 0.4.x built
-anyway.  Import them from here (or from jax_compat directly) instead of
-``jax.sharding`` so module import never fails on the installed JAX.
 """
 
 from __future__ import annotations
 
 import jax
-
-from repro.jax_compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Whatever-is-available mesh for local smoke runs."""
     n = len(jax.devices())
     model = min(model, n)
-    return make_mesh((n // model, model), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import pcast, shard_map
-
 
 def pipeline_forward(stage_fn, params_per_stage, x, *, mesh, n_microbatches,
                      stage_axis: str = "stage"):
@@ -42,10 +40,10 @@ def pipeline_forward(stage_fn, params_per_stage, x, *, mesh, n_microbatches,
         stage_id = jax.lax.axis_index(stage_axis)
         mb = xs.shape[1]
         # mark carries as stage-varying (shard_map vma typing): the loop body
-        # writes stage-dependent values into them (identity pre-vma JAX)
-        buf = pcast(jnp.zeros((mb,) + xs.shape[2:], xs.dtype),
+        # writes stage-dependent values into them
+        buf = jax.lax.pcast(jnp.zeros((mb,) + xs.shape[2:], xs.dtype),
                     (stage_axis,), to="varying")
-        outs = pcast(jnp.zeros_like(xs), (stage_axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), (stage_axis,), to="varying")
 
         def tick(t, carry):
             buf, outs = carry
@@ -69,7 +67,7 @@ def pipeline_forward(stage_fn, params_per_stage, x, *, mesh, n_microbatches,
             stage_axis)
         return outs
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(stage_axis), P(None)),
         out_specs=P(None),

@@ -15,9 +15,8 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field, replace
 
 import jax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.jax_compat import get_abstract_mesh
 
 MESH_AXES = ("pod", "data", "model")
 
@@ -77,7 +76,7 @@ def logical(*axes: str | None) -> P:
     axes that exist in the current mesh (so single-pod meshes accept
     ('pod','data') batch rules transparently)."""
     spec = current_rules().spec(*axes)
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return spec
     names = set(mesh.axis_names)
@@ -93,10 +92,21 @@ def logical(*axes: str | None) -> P:
     return P(*(prune(e) for e in spec))
 
 
-def _current_mesh():
-    # version-guarded: jax.sharding.get_abstract_mesh on new JAX, the
-    # thread-local physical mesh (``with Mesh(...):``) on 0.4.x
-    return get_abstract_mesh()
+def current_mesh():
+    """The mesh activated by ``jax.set_mesh``, or None outside any mesh
+    context (where JAX reports an empty abstract mesh)."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
+
+
+def tree_as_shardings(mesh, tree):
+    """Wrap PartitionSpec leaves into NamedSharding on ``mesh`` (None leaves
+    pass through), so ``jit`` shardings need no mesh context."""
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s) if isinstance(s, P) else s,
+        tree,
+        is_leaf=lambda x: isinstance(x, P) or x is None,
+    )
 
 
 def shard(x, *axes: str | None):
@@ -104,7 +114,7 @@ def shard(x, *axes: str | None):
     tracing without a mesh).  Axes whose mesh-shard product does not divide
     the tensor dim are pruned — e.g. a 51865-entry vocab stays unsharded on a
     16-way model axis, and batch=1 long-context decode replicates batch."""
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return x
     spec = prune_spec_for_shape(logical(*axes), x.shape, mesh)

@@ -16,11 +16,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import checkpoint as ckpt
-from repro.jax_compat import set_mesh, tree_as_shardings
 from repro.models import transformer as TF
 from repro.models import model_zoo as zoo
 from repro.optim import adamw, schedules
-from repro.parallel.sharding import ShardingRules, logical, param_specs, use_rules
+from repro.parallel.sharding import (ShardingRules, logical, param_specs,
+                                     tree_as_shardings, use_rules)
 from repro.runtime.straggler import StragglerDetector
 
 
@@ -81,14 +81,11 @@ class Trainer:
                 "loss": jnp.mean(losses), "xent": jnp.mean(xents),
                 "lr": lr, **om}
 
-        with set_mesh(self.mesh), use_rules(self.rules):
+        with jax.set_mesh(self.mesh), use_rules(self.rules):
             axes = TF.param_axes(cfg)
             pspecs = param_specs(axes)
             ospecs = adamw.state_axes(pspecs)
             bspec = logical("batch", None)
-            # PartitionSpecs wrapped into NamedShardings: 0.4.x jit accepts
-            # only Sharding instances (jax_compat), and it is a no-op upgrade
-            # on new JAX
             self.param_shardings = psh = tree_as_shardings(self.mesh, pspecs)
             osh = tree_as_shardings(self.mesh, ospecs)
             bsh = tree_as_shardings(
@@ -104,7 +101,7 @@ class Trainer:
     # ------------------------------------------------------------------
     def init_state(self, seed: int = 0):
         cfg = self.cfg
-        with set_mesh(self.mesh), use_rules(self.rules):
+        with jax.set_mesh(self.mesh), use_rules(self.rules):
             pspecs = param_specs(TF.param_axes(cfg))
             init = jax.jit(lambda k: TF.init_params(cfg, k),
                            out_shardings=tree_as_shardings(self.mesh, pspecs))
@@ -130,7 +127,7 @@ class Trainer:
             params, opt_state = self.init_state()
             params, opt_state, start_step = self.maybe_resume(params, opt_state)
         tc = self.tc
-        with set_mesh(self.mesh), use_rules(self.rules):
+        with jax.set_mesh(self.mesh), use_rules(self.rules):
             for step in range(start_step, tc.steps):
                 batch = source.batch(step)
                 t0 = time.perf_counter()

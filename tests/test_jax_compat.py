@@ -1,38 +1,38 @@
-"""JAX compat shims (`repro.jax_compat`) resolve on the installed JAX.
+"""The sharding API the model/runtime stack calls resolves on installed JAX.
 
-The model/runtime stack targets the post-0.6 sharding API; the environment
-pins 0.4.37, which has none of it.  These tests pin the shim contract on
-whatever JAX is installed: every symbol resolves, mesh construction and
-activation work without the new-API names, and `shard` / `logical` resolve
-PartitionSpecs against the active mesh through the version-guarded
-``get_abstract_mesh`` fallback.
+The stack calls current JAX directly (``jax.make_mesh`` with axis types,
+``jax.set_mesh``, ``jax.shard_map``, ``jax.lax.pcast``); the two helpers it
+still needs on top — `current_mesh` (None outside a mesh context) and
+`tree_as_shardings` — live in `repro.parallel.sharding`.  These tests pin
+that surface: mesh construction and activation, and `shard` / `logical`
+resolving PartitionSpecs against the active mesh.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType, NamedSharding
+from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat as jc
+from repro.parallel.sharding import current_mesh, tree_as_shardings
 
 
 def test_axis_type_and_make_mesh_resolve():
-    # importing AxisType must never fail, installed version regardless
-    assert hasattr(jc.AxisType, "Auto")
-    mesh = jc.make_mesh((1, 1), ("data", "model"),
-                        axis_types=(jc.AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     assert mesh.axis_names == ("data", "model")
     assert dict(mesh.shape) == {"data": 1, "model": 1}
 
 
 def test_get_abstract_mesh_tracks_mesh_context():
-    assert jc.get_abstract_mesh() is None
-    mesh = jc.make_mesh((1, 1), ("data", "model"))
-    with jc.set_mesh(mesh):
-        m = jc.get_abstract_mesh()
+    assert current_mesh() is None
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
+        m = current_mesh()
         assert m is not None
         assert tuple(m.axis_names) == ("data", "model")
-    assert jc.get_abstract_mesh() is None
+    assert current_mesh() is None
 
 
 def test_shard_and_logical_work_without_new_api_symbols():
@@ -42,17 +42,21 @@ def test_shard_and_logical_work_without_new_api_symbols():
     # no mesh in scope: shard is the identity (the arch-smoke path)
     np.testing.assert_array_equal(np.asarray(shard(x, "batch", None)),
                                   np.asarray(x))
-    mesh = jc.make_mesh((1, 1), ("data", "model"))
-    with jc.set_mesh(mesh):
+    # Auto axes, as the production mesh builds them: on Explicit axes (the
+    # make_mesh default) a sharding constraint asserts instead of resharding
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+    with jax.set_mesh(mesh):
         spec = logical("batch", "mlp")
-        # the ("pod", "data") batch rule prunes to the in-mesh axes
-        assert tuple(spec) == (("data",), "model")
+        # the ("pod", "data") batch rule prunes to the in-mesh axes; JAX
+        # normalises the one-axis tuple ("data",) to "data"
+        assert spec == P(("data",), "model")
+        assert tuple(spec) == ("data", "model")
         y = jax.jit(lambda t: shard(t, "batch", "mlp"))(x)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
 
 def test_mesh_module_imports_and_builds_host_mesh():
-    # the seed failed at `from jax.sharding import AxisType` module scope
     from repro.launch.mesh import make_host_mesh
 
     mesh = make_host_mesh()
@@ -60,35 +64,33 @@ def test_mesh_module_imports_and_builds_host_mesh():
 
 
 def test_tree_as_shardings_wraps_specs_for_jit():
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    mesh = jc.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
     tree = {"w": P("data", None), "b": None, "n": (P("model"), None)}
-    out = jc.tree_as_shardings(mesh, tree)
+    out = tree_as_shardings(mesh, tree)
     assert isinstance(out["w"], NamedSharding)
     assert out["b"] is None
     assert isinstance(out["n"][0], NamedSharding) and out["n"][1] is None
-    # the wrapped tree is jit-accepted on every version (the 0.4.x failure
-    # mode was jit rejecting raw PartitionSpecs)
+    # the wrapped shardings are jit-accepted with no mesh context
     f = jax.jit(lambda x: x + 1, in_shardings=out["w"], out_shardings=out["w"])
     np.testing.assert_array_equal(np.asarray(f(jnp.zeros((2, 2)))),
                                   np.ones((2, 2)))
 
 
 def test_pcast_and_shard_map_resolve():
-    from jax.sharding import PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("stage",))
 
-    assert np.asarray(jc.pcast(jnp.ones(3), ("data",))).sum() == 3
-    mesh = jc.make_mesh((1,), ("stage",))
-    f = jc.shard_map(lambda x: x * 2, mesh=mesh, in_specs=(P("stage"),),
-                     out_specs=P("stage"))
+    def body(x):
+        return jax.lax.pcast(jnp.ones(3), ("stage",), to="varying").sum() * x
+
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("stage"),),
+                      out_specs=P("stage"))
     np.testing.assert_array_equal(np.asarray(f(jnp.arange(4.0))),
-                                  np.arange(4.0) * 2)
+                                  np.arange(4.0) * 3)
 
 
 def test_set_mesh_usable_as_context_manager():
-    mesh = jc.make_mesh((1, 1), ("data", "model"))
-    with jc.set_mesh(mesh):
-        pass  # old JAX: the Mesh itself; new JAX: jax.set_mesh's manager
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
+        pass
     with pytest.raises(ValueError):
-        jc.make_mesh((7, 3), ("a", "b"))  # device count mismatch still raises
+        jax.make_mesh((7, 3), ("a", "b"))  # device count mismatch still raises

@@ -178,10 +178,12 @@ def test_depart_times_int64_rebase():
 # serve round (full engine round as a (max,+) affine scan)
 # ---------------------------------------------------------------------------
 
-from repro.core.engine import SimOptions, simulate as engine_simulate  # noqa: E402
+from repro.core.engine import (  # noqa: E402
+    Channels, Hops, SimOptions, simulate as engine_simulate)
 from repro.core.ref_des import simulate_ref  # noqa: E402
 from repro.core.streaming import simulate_stream, stream_windows  # noqa: E402
-from repro.kernels.serve_round.kernel import NEG, serve_scan  # noqa: E402
+from repro.kernels.serve_round.kernel import (  # noqa: E402
+    NEG, SPAN_LIMIT, serve_scan)
 from repro.kernels.serve_round.ref import serve_scan_ref  # noqa: E402
 
 
@@ -234,9 +236,10 @@ def test_serve_scan_property(k, seed):
     c0 = np.where(kind == 0, arr, c0)
     c1 = np.where(kind == 0, arr + np.where(has_r, r, 0), c1)
     args = [jnp.asarray(a) for a in (m00, m01, m10, m11, c0, c1)]
-    out = serve_scan(*args, blk=64, interpret=True)
+    out, hi = serve_scan(*args, blk=64, interpret=True)
     ref = serve_scan_ref(*args)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
+    assert int(hi) <= SPAN_LIMIT
 
 
 def _engine_case(seed, **kw):
@@ -316,3 +319,89 @@ def test_serve_round_kernel_stream_carry():
     k = col["item_hop"].astype(np.int64)
     assert np.array_equal(col["item_depart"], np.asarray(mono.depart)[r, k])
     assert np.array_equal(col["item_start"], np.asarray(mono.start)[r, k])
+
+
+@pytest.mark.parametrize("blk", [256, 2048])
+def test_serve_scan_tiled_blocks(blk):
+    """Blocks of several (rows, 128) tiles — the layout the chip runs —
+    compose across lane wraps, sublane shifts and block carries exactly
+    like the sequential oracle."""
+    rng = np.random.default_rng(blk)
+    k = 3 * blk + 77
+    s = rng.integers(0, 1 << 12, k).astype(np.int32)
+    arr = np.sort(rng.integers(0, 1 << 22, k)).astype(np.int32)
+    head = rng.random(k) < 0.05
+    head[0] = True
+    neg = np.full(k, NEG, np.int32)
+    m00 = np.where(head, neg, s)
+    m01 = np.where(head, neg, s)
+    m10 = neg.copy()
+    m11 = np.where(head, neg, 0).astype(np.int32)
+    c0 = arr + s
+    c1 = np.where(head, arr, neg)
+    args = [jnp.asarray(a) for a in (m00, m01, m10, m11, c0, c1)]
+    out, hi = serve_scan(*args, blk=blk, interpret=True)
+    assert np.array_equal(np.asarray(out), np.asarray(serve_scan_ref(*args)))
+    assert int(hi) <= SPAN_LIMIT
+
+
+def _one_channel_round(arrive, ser, impl):
+    """`serve_round` on K serving items queued on one cold channel."""
+    from repro.kernels.serve_round.ops import serve_round
+
+    k = len(arrive)
+    i64 = lambda x: jnp.asarray(np.asarray(x, np.int64))  # noqa: E731
+    zero = i64(np.zeros(k))
+    return serve_round(
+        jnp.zeros(k, jnp.int32), jnp.ones(k, bool), jnp.zeros(k, bool),
+        i64(arrive), jnp.zeros(k, jnp.int8), jnp.full(k, -1, jnp.int32),
+        i64(ser), zero, zero, zero, zero, zero, jnp.full(k, -1, jnp.int8),
+        jnp.full(k, -2, jnp.int32), zero, impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("arrive,ser,ok", [
+    ([0, 10, 20], [100, 100, 100], True),
+    # queueing composes a depart of 3 * 2**28 > 2**29
+    ([0, 0, 0], [1 << 28] * 3, False),
+    # the rebased arrivals alone leave int32
+    ([0, (1 << 31) + 5, (1 << 31) + 9], [100, 100, 100], False),
+])
+def test_serve_round_span_contract(impl, arrive, ser, ok):
+    """A round over the 2**29 span is flagged, whichever way it breaks the
+    contract; an in-contract round is exact."""
+    start, depart, _, got = _one_channel_round(arrive, ser, impl)
+    assert bool(got) is ok
+    if ok:
+        assert np.asarray(depart).tolist() == [100, 200, 300]
+        assert np.asarray(start).tolist() == [0, 100, 200]
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("issue,nbytes", [
+    ([0, 0, 0, 0], 300_000),              # queued departs past 2**29 ps
+    ([0, 3_000_000_000, 3_000_000_100, 3_000_000_200], 64),  # > int32 span
+])
+def test_serve_round_over_span_never_returns_kernel_schedule(impl, issue,
+                                                             nbytes):
+    """Synthetic over-span workloads: every round the kernel flags is
+    resolved on the int64 lax path, so the schedule equals the lax engine
+    and the event-driven oracle exactly."""
+    n = len(issue)
+    ch = Channels(jnp.asarray([1000], jnp.int64), jnp.zeros(1, jnp.int64),
+                  jnp.zeros(1, jnp.int64), jnp.zeros(1, jnp.int64))
+    ones = np.ones((n, 1))
+    hops = Hops(jnp.zeros((n, 1), jnp.int32),
+                jnp.asarray(ones * nbytes, jnp.int64),
+                jnp.zeros((n, 1), jnp.int8), jnp.full((n, 1), -1, jnp.int32),
+                jnp.asarray(ones * 500, jnp.int64), jnp.ones((n, 1), bool),
+                jnp.ones((n, 1), bool))
+    iss = jnp.asarray(issue, jnp.int64)
+    lax_s = engine_simulate(hops, ch, iss)
+    ker_s = engine_simulate(hops, ch, iss, SimOptions(use_kernel=impl))
+    ref = simulate_ref(hops, ch, np.asarray(issue, np.int64))
+    assert int(jnp.max(lax_s.arrive)) - min(issue) > SPAN_LIMIT
+    for f in ("start", "depart", "arrive", "complete"):
+        assert np.array_equal(np.asarray(getattr(lax_s, f)),
+                              np.asarray(getattr(ker_s, f))), f
+    assert np.array_equal(np.asarray(ker_s.complete), ref["complete"])

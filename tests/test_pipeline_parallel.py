@@ -12,10 +12,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 import numpy as np
-from repro.jax_compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.parallel.pipeline_par import pipeline_forward
 
-mesh = make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
+mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 S, D = 4, 16
 rng = np.random.default_rng(0)
 ws = jnp.asarray(rng.normal(0, 0.5, (S, D, D)).astype(np.float32))

@@ -86,6 +86,9 @@ def _reliability_case(seed):
                      rng.integers(0, 8, (n, h)) * 256, 0).astype(np.int64)
     retrain = np.where(rng.random((n, h)) < .2,
                        rng.integers(1, 4, (n, h)) * 100_000, 0).astype(np.int64)
+    # a zero-byte retrain hop is a link-down marker, which the lowering
+    # contract (`verify`, rel.marker) allows on full-duplex channels only
+    retrain = np.where((nbytes == 0) & (turn[chan] != 0), 0, retrain)
     hops = Hops(jnp.asarray(chan), jnp.asarray(nbytes),
                 jnp.asarray(rng.integers(0, 2, (n, h)).astype(np.int8)),
                 jnp.asarray(np.full((n, h), -1, np.int32)),
