@@ -29,6 +29,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -307,153 +308,160 @@ def build_workload(
             "with_flit(topo, ...)) instead of overriding at workload level")
     flit_fec_ps = flit_cfg.fec_latency_ps if flit_cfg.active else 0
 
-    rows: list[dict] = []
-    tx = 0
-    for spec in specs:
-        rng = np.random.default_rng(spec.seed + 7919 * spec.node)
-        addr, wr, iv = _gen_addresses(spec, rng)
-        tgt = _interleave(addr, spec.targets, interleave)
-        t = spec.start_ps + np.cumsum(iv) - iv[0]
-        for i in range(spec.n_requests):
-            rows.append(dict(
-                req=spec.node, mem=int(tgt[i]), write=bool(wr[i]),
-                addr=int(addr[i]), issue=int(t[i]) + requester_overhead_ps,
-                payload=spec.payload_bytes, idx=tx, ntgt=len(spec.targets),
-                measured=i >= int(spec.n_requests * warmup_frac),
-            ))
-            tx += 1
+    # one host span per lowering phase (`core.spans`), never one per
+    # request: each shows on a profiler trace beside the device's work
+    span = jax.profiler.TraceAnnotation
+    with span("lower.requests"):
+        rows: list[dict] = []
+        tx = 0
+        for spec in specs:
+            rng = np.random.default_rng(spec.seed + 7919 * spec.node)
+            addr, wr, iv = _gen_addresses(spec, rng)
+            tgt = _interleave(addr, spec.targets, interleave)
+            t = spec.start_ps + np.cumsum(iv) - iv[0]
+            for i in range(spec.n_requests):
+                rows.append(dict(
+                    req=spec.node, mem=int(tgt[i]), write=bool(wr[i]),
+                    addr=int(addr[i]), issue=int(t[i]) + requester_overhead_ps,
+                    payload=spec.payload_bytes, idx=tx, ntgt=len(spec.targets),
+                    measured=i >= int(spec.n_requests * warmup_frac),
+                ))
+                tx += 1
 
-    n = len(rows)
-    # resolve routes; longest path defines padding
-    paths = []
-    alts = np.zeros(n, dtype=np.int64)
-    for j, r in enumerate(rows):
-        alt = int(route_choice[j]) if route_choice is not None else 0
-        alts[j] = alt % graph.n_route_alternatives(r["req"], r["mem"])
-        paths.append(graph.route(r["req"], r["mem"], alt=alt))
-    max_links = max(len(p) - 1 for p in paths)
-    h = 2 * max_links + 1  # request hops + service + response hops
+    with span("lower.routes"):
+        n = len(rows)
+        # resolve routes; longest path defines padding
+        paths = []
+        alts = np.zeros(n, dtype=np.int64)
+        for j, r in enumerate(rows):
+            alt = int(route_choice[j]) if route_choice is not None else 0
+            alts[j] = alt % graph.n_route_alternatives(r["req"], r["mem"])
+            paths.append(graph.route(r["req"], r["mem"], alt=alt))
+    with span("lower.hops"):
+        max_links = max(len(p) - 1 for p in paths)
+        h = 2 * max_links + 1  # request hops + service + response hops
 
-    channel = np.full((n, h), -1, dtype=np.int32)
-    nbytes = np.zeros((n, h), dtype=np.int64)
-    direction = np.zeros((n, h), dtype=np.int8)
-    row_id = np.full((n, h), -1, dtype=np.int32)
-    fixed_after = np.zeros((n, h), dtype=np.int64)
-    is_payload = np.zeros((n, h), dtype=bool)
-    valid = np.zeros((n, h), dtype=bool)
+        channel = np.full((n, h), -1, dtype=np.int32)
+        nbytes = np.zeros((n, h), dtype=np.int64)
+        direction = np.zeros((n, h), dtype=np.int8)
+        row_id = np.full((n, h), -1, dtype=np.int32)
+        fixed_after = np.zeros((n, h), dtype=np.int64)
+        is_payload = np.zeros((n, h), dtype=bool)
+        valid = np.zeros((n, h), dtype=bool)
 
-    sw_ps = graph.topo.switching_ps
-    for j, (r, path) in enumerate(zip(rows, paths)):
-        write = r["write"]
-        pay = r["payload"]
-        fwd_b, bwd_b, fwd_pay, bwd_pay = packetize(
-            header_model, write, pay, header_bytes)
-        k = 0
-        for u, v in zip(path[:-1], path[1:]):
-            c, d = graph.edge_channel(u, v)
+        sw_ps = graph.topo.switching_ps
+        for j, (r, path) in enumerate(zip(rows, paths)):
+            write = r["write"]
+            pay = r["payload"]
+            fwd_b, bwd_b, fwd_pay, bwd_pay = packetize(
+                header_model, write, pay, header_bytes)
+            k = 0
+            for u, v in zip(path[:-1], path[1:]):
+                c, d = graph.edge_channel(u, v)
+                channel[j, k] = c
+                nbytes[j, k] = fwd_b
+                direction[j, k] = d
+                fixed_after[j, k] = (graph.chan_fixed_ps[c] + flit_fec_ps
+                                     + (sw_ps if graph.topo.kinds[v] == SWITCH else 0))
+                is_payload[j, k] = fwd_pay
+                valid[j, k] = True
+                k += 1
+            # endpoint service hop (banked; row-buffer state carried per bank).
+            # The line-interleave across endpoints consumes the low addr bits, so
+            # bank/row derive from the per-endpoint line index (addr // n_targets)
+            # — otherwise every request to an endpoint would land in one bank.
+            ep_line = r["addr"] // max(r["ntgt"], 1)
+            bank = ep_line % ep.banks
+            c = graph.service_channel(r["mem"], bank)
             channel[j, k] = c
-            nbytes[j, k] = fwd_b
-            direction[j, k] = d
-            fixed_after[j, k] = (graph.chan_fixed_ps[c] + flit_fec_ps
-                                 + (sw_ps if graph.topo.kinds[v] == SWITCH else 0))
-            is_payload[j, k] = fwd_pay
+            nbytes[j, k] = pay
+            row_id[j, k] = (ep_line // ep.lines_per_row) % (1 << 30)
+            fixed_after[j, k] = ep.fixed_ps
+            is_payload[j, k] = True
             valid[j, k] = True
             k += 1
-        # endpoint service hop (banked; row-buffer state carried per bank).
-        # The line-interleave across endpoints consumes the low addr bits, so
-        # bank/row derive from the per-endpoint line index (addr // n_targets)
-        # — otherwise every request to an endpoint would land in one bank.
-        ep_line = r["addr"] // max(r["ntgt"], 1)
-        bank = ep_line % ep.banks
-        c = graph.service_channel(r["mem"], bank)
-        channel[j, k] = c
-        nbytes[j, k] = pay
-        row_id[j, k] = (ep_line // ep.lines_per_row) % (1 << 30)
-        fixed_after[j, k] = ep.fixed_ps
-        is_payload[j, k] = True
-        valid[j, k] = True
-        k += 1
-        for u, v in zip(path[::-1][:-1], path[::-1][1:]):
-            c, d = graph.edge_channel(u, v)
-            channel[j, k] = c
-            nbytes[j, k] = bwd_b
-            direction[j, k] = d
-            fixed_after[j, k] = (graph.chan_fixed_ps[c] + flit_fec_ps
-                                 + (sw_ps if graph.topo.kinds[v] == SWITCH else 0))
-            is_payload[j, k] = bwd_pay
-            valid[j, k] = True
-            k += 1
+            for u, v in zip(path[::-1][:-1], path[::-1][1:]):
+                c, d = graph.edge_channel(u, v)
+                channel[j, k] = c
+                nbytes[j, k] = bwd_b
+                direction[j, k] = d
+                fixed_after[j, k] = (graph.chan_fixed_ps[c] + flit_fec_ps
+                                     + (sw_ps if graph.topo.kinds[v] == SWITCH else 0))
+                is_payload[j, k] = bwd_pay
+                valid[j, k] = True
+                k += 1
 
-    # ---- credit-return DLLP traffic (FlitConfig(credit_dllp=True)) -------
-    # every credit-return window of flits transmitted on a full-duplex flit
-    # channel emits one DLLP-sized hop on the paired reverse channel, issued
-    # with the transaction that crossed the window boundary (build-time
-    # approximation) — credit starvation couples to reverse congestion.
-    dllp = _credit_dllp_plan(graph, flit_cfg)
-    if dllp is not None:
-        from .calibration import CREDIT_DLLP_B
+        # ---- credit-return DLLP traffic (FlitConfig(credit_dllp=True)) -------
+        # every credit-return window of flits transmitted on a full-duplex flit
+        # channel emits one DLLP-sized hop on the paired reverse channel, issued
+        # with the transaction that crossed the window boundary (build-time
+        # approximation) — credit starvation couples to reverse congestion.
+        dllp = _credit_dllp_plan(graph, flit_cfg)
+        if dllp is not None:
+            from .calibration import CREDIT_DLLP_B
 
-        d_mask, d_win, d_pay = dllp
-        cum = np.zeros(graph.n_channels, np.int64)
-        d_rows: list[tuple[int, int]] = []   # (issue_ps, reverse channel)
-        # accumulate in issue-time order, not build (requester-major) order,
-        # so each window's DLLP is stamped with the transaction that
-        # actually crossed it when several requesters share a channel
-        order = np.argsort([r["issue"] for r in rows], kind="stable")
-        for j in order:
-            for k in range(h):
-                c = channel[j, k]
-                if not valid[j, k] or c < 0 or not d_mask[c] \
-                        or nbytes[j, k] <= 0:
-                    continue
-                cum[c] += -(-nbytes[j, k] // d_pay[c])
-                while cum[c] >= d_win[c]:
-                    cum[c] -= d_win[c]
-                    d_rows.append((rows[j]["issue"], int(graph.chan_pair[c])))
-        if d_rows:
-            m = len(d_rows)
-            channel = np.vstack([channel, np.full((m, h), -1, np.int32)])
-            nbytes = np.vstack([nbytes, np.zeros((m, h), np.int64)])
-            direction = np.vstack([direction, np.zeros((m, h), np.int8)])
-            row_id = np.vstack([row_id, np.full((m, h), -1, np.int32)])
-            fixed_after = np.vstack([fixed_after, np.zeros((m, h), np.int64)])
-            is_payload = np.vstack([is_payload, np.zeros((m, h), bool)])
-            valid = np.vstack([valid, np.zeros((m, h), bool)])
-            for i, (iss, rc) in enumerate(d_rows):
-                channel[n + i, 0] = rc
-                nbytes[n + i, 0] = CREDIT_DLLP_B
-                # same per-hop fixed cost as every other hop on this path
-                # (flit_fec_ps is nonzero only on the override path; the
-                # graph-carried path bakes FEC into chan_fixed_ps)
-                fixed_after[n + i, 0] = graph.chan_fixed_ps[rc] + flit_fec_ps
-                valid[n + i, 0] = True
-                rows.append(dict(req=-1, mem=-1, write=False, addr=0,
-                                 issue=iss, payload=0, idx=n + i, ntgt=1,
-                                 measured=False))
-                paths.append([-1, -1])
-            alts = np.concatenate([alts, np.zeros(m, np.int64)])
-            n += m
+            d_mask, d_win, d_pay = dllp
+            cum = np.zeros(graph.n_channels, np.int64)
+            d_rows: list[tuple[int, int]] = []   # (issue_ps, reverse channel)
+            # accumulate in issue-time order, not build (requester-major) order,
+            # so each window's DLLP is stamped with the transaction that
+            # actually crossed it when several requesters share a channel
+            order = np.argsort([r["issue"] for r in rows], kind="stable")
+            for j in order:
+                for k in range(h):
+                    c = channel[j, k]
+                    if not valid[j, k] or c < 0 or not d_mask[c] \
+                            or nbytes[j, k] <= 0:
+                        continue
+                    cum[c] += -(-nbytes[j, k] // d_pay[c])
+                    while cum[c] >= d_win[c]:
+                        cum[c] -= d_win[c]
+                        d_rows.append((rows[j]["issue"], int(graph.chan_pair[c])))
+            if d_rows:
+                m = len(d_rows)
+                channel = np.vstack([channel, np.full((m, h), -1, np.int32)])
+                nbytes = np.vstack([nbytes, np.zeros((m, h), np.int64)])
+                direction = np.vstack([direction, np.zeros((m, h), np.int8)])
+                row_id = np.vstack([row_id, np.full((m, h), -1, np.int32)])
+                fixed_after = np.vstack([fixed_after, np.zeros((m, h), np.int64)])
+                is_payload = np.vstack([is_payload, np.zeros((m, h), bool)])
+                valid = np.vstack([valid, np.zeros((m, h), bool)])
+                for i, (iss, rc) in enumerate(d_rows):
+                    channel[n + i, 0] = rc
+                    nbytes[n + i, 0] = CREDIT_DLLP_B
+                    # same per-hop fixed cost as every other hop on this path
+                    # (flit_fec_ps is nonzero only on the override path; the
+                    # graph-carried path bakes FEC into chan_fixed_ps)
+                    fixed_after[n + i, 0] = graph.chan_fixed_ps[rc] + flit_fec_ps
+                    valid[n + i, 0] = True
+                    rows.append(dict(req=-1, mem=-1, write=False, addr=0,
+                                     issue=iss, payload=0, idx=n + i, ntgt=1,
+                                     measured=False))
+                    paths.append([-1, -1])
+                alts = np.concatenate([alts, np.zeros(m, np.int64)])
+                n += m
 
-    # stochastic link reliability: sample the per-hop replay/retraining
-    # tables from the seeded per-channel streams (build time, like issue
-    # jitter, so sweeps can stack the sampled tables and vmap) and mirror
-    # full-duplex retraining stalls onto the paired channel.  The
-    # expected-value mode leaves Hops in the PR-1 layout untouched.
-    hops = finish_hops(graph, flit_cfg, channel, nbytes, direction, row_id,
-                       fixed_after, is_payload, valid)
-    channels = make_channels(graph, ep.row_hit_extra_ps, ep.row_miss_extra_ps)
-    if flit_cfg.active:
-        channels = link_layer.apply_flit(
-            channels, ~graph.chan_is_service, flit_cfg)
-    return Workload(
-        hops=hops,
-        channels=channels,
-        issue_ps=jnp.asarray(np.array([r["issue"] for r in rows], np.int64)),
-        payload_bytes=jnp.asarray(np.array([r["payload"] for r in rows], np.int64)),
-        measured=jnp.asarray(np.array([r["measured"] for r in rows], bool)),
-        requester=np.array([r["req"] for r in rows], np.int64),
-        target=np.array([r["mem"] for r in rows], np.int64),
-        is_write=np.array([r["write"] for r in rows], bool),
-        n_link_hops=np.array([len(p) - 1 for p in paths], np.int64),
-        route_alt=alts,
-    )
+    with span("lower.finish"):
+        # stochastic link reliability: sample the per-hop replay/retraining
+        # tables from the seeded per-channel streams (build time, like issue
+        # jitter, so sweeps can stack the sampled tables and vmap) and mirror
+        # full-duplex retraining stalls onto the paired channel.  The
+        # expected-value mode leaves Hops in the PR-1 layout untouched.
+        hops = finish_hops(graph, flit_cfg, channel, nbytes, direction, row_id,
+                           fixed_after, is_payload, valid)
+        channels = make_channels(graph, ep.row_hit_extra_ps, ep.row_miss_extra_ps)
+        if flit_cfg.active:
+            channels = link_layer.apply_flit(
+                channels, ~graph.chan_is_service, flit_cfg)
+        return Workload(
+            hops=hops,
+            channels=channels,
+            issue_ps=jnp.asarray(np.array([r["issue"] for r in rows], np.int64)),
+            payload_bytes=jnp.asarray(np.array([r["payload"] for r in rows], np.int64)),
+            measured=jnp.asarray(np.array([r["measured"] for r in rows], bool)),
+            requester=np.array([r["req"] for r in rows], np.int64),
+            target=np.array([r["mem"] for r in rows], np.int64),
+            is_write=np.array([r["write"] for r in rows], bool),
+            n_link_hops=np.array([len(p) - 1 for p in paths], np.int64),
+            route_alt=alts,
+        )

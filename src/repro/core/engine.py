@@ -373,36 +373,41 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, ser,
     flat_arrive = arrive[:, :h].reshape(k)
     flat_chan = hops.channel.reshape(k)
     flat_valid = hops.valid.reshape(k)
-    # push invalid items to a dummy tail segment so they never contend
-    sort_chan = jnp.where(flat_valid, flat_chan, jnp.int32(ch.bw_MBps.shape[0]))
+    # the round's steps carry named scopes (`core.spans`): ``round.order``,
+    # ``round.gather``, ``round.serve`` and ``round.scatter`` in the op_name
+    # metadata of the compiled program, so a profile splits a round by step
+    with jax.named_scope("round.order"):
+        # push invalid items to a dummy tail segment so they never contend
+        sort_chan = jnp.where(flat_valid, flat_chan,
+                              jnp.int32(ch.bw_MBps.shape[0]))
+        order = channel_time_order(sort_chan, flat_arrive,
+                                   ch.bw_MBps.shape[0] + 1)
 
-    order = channel_time_order(sort_chan, flat_arrive,
-                               ch.bw_MBps.shape[0] + 1)
-
-    chan_clipped = jnp.minimum(flat_chan[order], ch.bw_MBps.shape[0] - 1)
-    s_chan = flat_chan[order]
-    s_valid = flat_valid[order]
-    s_arrive = flat_arrive[order]
-    s_dir = hops.direction.reshape(k)[order]
-    s_row = hops.row.reshape(k)[order]
-    s_bytes = hops.nbytes.reshape(k)[order]
-    s_ser = ser.reshape(k)[order]
-    s_turn = ch.turnaround_ps[chan_clipped]
-    s_rowhit = ch.row_hit_ps[chan_clipped]
-    s_rowmiss = ch.row_miss_ps[chan_clipped]
     # stochastic retraining stalls extend the carry with per-channel
     # down-until state — resolved at trace time so the deterministic layout
     # compiles to the exact PR-1 scan
     has_retrain = hops.retrain_after_ps is not None
     has_carry = carry is not None
-    xs = (s_chan, s_valid, s_arrive, s_dir, s_row, s_ser, s_turn, s_rowhit,
-          s_rowmiss, s_bytes)
-    if has_retrain:
-        xs = xs + (hops.retrain_after_ps.reshape(k)[order],)
-    if has_carry:
-        seed_ix = jnp.clip(s_chan, 0, ch.bw_MBps.shape[0] - 1)
-        xs = xs + (carry.depart_ps[seed_ix], carry.last_dir[seed_ix],
-                   carry.last_row[seed_ix], carry.down_until_ps[seed_ix])
+    with jax.named_scope("round.gather"):
+        chan_clipped = jnp.minimum(flat_chan[order], ch.bw_MBps.shape[0] - 1)
+        s_chan = flat_chan[order]
+        s_valid = flat_valid[order]
+        s_arrive = flat_arrive[order]
+        s_dir = hops.direction.reshape(k)[order]
+        s_row = hops.row.reshape(k)[order]
+        s_bytes = hops.nbytes.reshape(k)[order]
+        s_ser = ser.reshape(k)[order]
+        s_turn = ch.turnaround_ps[chan_clipped]
+        s_rowhit = ch.row_hit_ps[chan_clipped]
+        s_rowmiss = ch.row_miss_ps[chan_clipped]
+        xs = (s_chan, s_valid, s_arrive, s_dir, s_row, s_ser, s_turn,
+              s_rowhit, s_rowmiss, s_bytes)
+        if has_retrain:
+            xs = xs + (hops.retrain_after_ps.reshape(k)[order],)
+        if has_carry:
+            seed_ix = jnp.clip(s_chan, 0, ch.bw_MBps.shape[0] - 1)
+            xs = xs + (carry.depart_ps[seed_ix], carry.last_dir[seed_ix],
+                       carry.last_row[seed_ix], carry.down_until_ps[seed_ix])
 
     def scan_fn(state, x):
         if has_retrain or has_carry:
@@ -528,7 +533,8 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, ser,
         return jax.lax.scan(scan_fn, init, xs)[1]
 
     if impl == "scan":
-        out = lax_round()
+        with jax.named_scope("round.serve"):
+            out = lax_round()
     else:
         # Pallas serve-round kernel (`kernels.serve_round`): one code path
         # for every layout — deterministic/no-carry configs ride the carry
@@ -538,24 +544,27 @@ def _one_round(hops: Hops, ch: Channels, issue_ps, arrive, ser,
         # lax path instead, so no over-span round returns a kernel schedule.
         from ..kernels.serve_round.ops import serve_round
 
-        s_retrain = (hops.retrain_after_ps.reshape(k)[order]
-                     if has_retrain else jnp.zeros(k, jnp.int64))
-        if has_carry:
-            seed_ix = jnp.clip(s_chan, 0, ch.bw_MBps.shape[0] - 1)
-            sd = (carry.depart_ps[seed_ix], carry.last_dir[seed_ix],
-                  carry.last_row[seed_ix], carry.down_until_ps[seed_ix])
-        else:
-            sd = (jnp.zeros(k, jnp.int64), jnp.full(k, -1, jnp.int8),
-                  jnp.full(k, -2, jnp.int32), jnp.zeros(k, jnp.int64))
-        serving = s_valid & (s_bytes > 0)
-        marker = s_valid & (s_bytes == 0) & (s_retrain > 0)
-        *kout, ok = serve_round(
-            s_chan, serving, marker, s_arrive, s_dir, s_row, s_ser,
-            s_turn, s_rowhit, s_rowmiss, s_retrain, *sd, impl=impl)
-        kout = tuple(kout[:3 if with_stalls else 2])
-        out = jax.lax.cond(ok, lambda: kout, lax_round)
-    return _scatter_round(hops, issue_ps, order, out[0], out[1],
-                          out[2] if with_stalls else None)
+        with jax.named_scope("round.gather"):
+            s_retrain = (hops.retrain_after_ps.reshape(k)[order]
+                         if has_retrain else jnp.zeros(k, jnp.int64))
+            if has_carry:
+                seed_ix = jnp.clip(s_chan, 0, ch.bw_MBps.shape[0] - 1)
+                sd = (carry.depart_ps[seed_ix], carry.last_dir[seed_ix],
+                      carry.last_row[seed_ix], carry.down_until_ps[seed_ix])
+            else:
+                sd = (jnp.zeros(k, jnp.int64), jnp.full(k, -1, jnp.int8),
+                      jnp.full(k, -2, jnp.int32), jnp.zeros(k, jnp.int64))
+        with jax.named_scope("round.serve"):
+            serving = s_valid & (s_bytes > 0)
+            marker = s_valid & (s_bytes == 0) & (s_retrain > 0)
+            *kout, ok = serve_round(
+                s_chan, serving, marker, s_arrive, s_dir, s_row, s_ser,
+                s_turn, s_rowhit, s_rowmiss, s_retrain, *sd, impl=impl)
+            kout = tuple(kout[:3 if with_stalls else 2])
+            out = jax.lax.cond(ok, lambda: kout, lax_round)
+    with jax.named_scope("round.scatter"):
+        return _scatter_round(hops, issue_ps, order, out[0], out[1],
+                              out[2] if with_stalls else None)
 
 
 def channel_time_order(chan, time, n_chan: int):

@@ -204,279 +204,291 @@ def _ensure_layout(state: StreamState, ck_hops: Hops) -> tuple:
 def _process_window(state: StreamState, channels: Channels, ck_hops: Hops,
                     ck_issue, t_next: int, opts: SimOptions, pad_to: int,
                     oracle_fallback: bool, collect: dict | None) -> None:
-    has_extra, has_retrain, has_join = _ensure_layout(state, ck_hops)
+    # one host span per phase of the window (`core.spans`): each shows on
+    # a profiler trace beside the device's work, so a device-idle gap
+    # can be put down to the host phase the device waits on
+    span = jax.profiler.TraceAnnotation
+    with span("window.assemble"):
+        has_extra, has_retrain, has_join = _ensure_layout(state, ck_hops)
 
-    c_np = {f: _np(getattr(ck_hops, f)) for f in _BASE_FIELDS}
-    if has_extra:
-        c_np["extra_wire_bytes"] = _np(ck_hops.extra_wire_bytes)
-    if has_retrain:
-        c_np["retrain_after_ps"] = _np(ck_hops.retrain_after_ps)
-    n_c, h_c = c_np["channel"].shape
-    c_issue = np.asarray(ck_issue, np.int64)
-    ci = state.chunk_idx
-    carried = state.carried
-    n_k = len(carried)
-    n_raw = n_k + n_c
+        c_np = {f: _np(getattr(ck_hops, f)) for f in _BASE_FIELDS}
+        if has_extra:
+            c_np["extra_wire_bytes"] = _np(ck_hops.extra_wire_bytes)
+        if has_retrain:
+            c_np["retrain_after_ps"] = _np(ck_hops.retrain_after_ps)
+        n_c, h_c = c_np["channel"].shape
+        c_issue = np.asarray(ck_issue, np.int64)
+        ci = state.chunk_idx
+        carried = state.carried
+        n_k = len(carried)
+        n_raw = n_k + n_c
 
-    # ---- window group-id space: carried groups first, then chunk groups
-    keys: dict = {}
-    if has_join:
-        for r in carried:
-            for key in (r["jwait"], r["jid"]):
-                if key is not None:
-                    keys.setdefault(key, len(keys))
-        cj = _np(ck_hops.join_id)
-        cw = _np(ck_hops.join_wait)
-        ca = _np(ck_hops.join_arity)
-        for g in np.unique(np.concatenate([cj[cj >= 0], cw[cw >= 0]])):
-            keys.setdefault((ci, int(g)), len(keys))
-    n_groups = len(keys)
-
-    n_pad = -(-max(n_raw, n_groups, 1) // pad_to) * pad_to
-    h_w = max([h_c, 1] + [r["hops"]["channel"].shape[0] for r in carried])
-
-    # ---- assemble the window: carried suffixes, chunk rows, padding
-    W = {
-        "channel": np.zeros((n_pad, h_w), np.int32),
-        "nbytes": np.zeros((n_pad, h_w), np.int64),
-        "direction": np.zeros((n_pad, h_w), np.int8),
-        "row": np.full((n_pad, h_w), -1, np.int32),
-        "fixed_after_ps": np.zeros((n_pad, h_w), np.int64),
-        "is_payload": np.zeros((n_pad, h_w), bool),
-        "valid": np.zeros((n_pad, h_w), bool),
-    }
-    if has_extra:
-        W["extra_wire_bytes"] = np.zeros((n_pad, h_w), np.int64)
-    if has_retrain:
-        W["retrain_after_ps"] = np.zeros((n_pad, h_w), np.int64)
-    issue_w = np.zeros(n_pad, np.int64)
-    orig_issue = np.zeros(n_pad, np.int64)
-    gid_w = np.full(n_pad, -1, np.int64)
-    hop0_w = np.zeros(n_pad, np.int64)
-    if has_join:
-        jid_w = np.full(n_pad, -1, np.int32)
-        jwait_w = np.full(n_pad, -1, np.int32)
-
-    for i, r in enumerate(carried):
-        length = r["hops"]["channel"].shape[0]
-        for f, a in r["hops"].items():
-            W[f][i, :length] = a
-        issue_w[i] = r["issue"]
-        orig_issue[i] = r["orig_issue"]
-        gid_w[i] = r["gid"]
-        hop0_w[i] = r["hop0"]
+        # ---- window group-id space: carried groups first, then chunk groups
+        keys: dict = {}
         if has_join:
-            if r["jid"] is not None:
-                jid_w[i] = keys[r["jid"]]
-            if r["jwait"] is not None:
-                jwait_w[i] = keys[r["jwait"]]
-    for f in W:
-        W[f][n_k:n_raw, :h_c] = c_np[f]
-    issue_w[n_k:n_raw] = c_issue
-    orig_issue[n_k:n_raw] = c_issue
-    gid_w[n_k:n_raw] = state.gid_next + np.arange(n_c)
-    state.gid_next += n_c
-    if has_join:
-        for src, dst in ((cj, jid_w), (cw, jwait_w)):
-            m = src >= 0
-            dst[n_k:n_raw][m] = np.fromiter(
-                (keys[(ci, int(g))] for g in src[m]), np.int32, int(m.sum()))
-        # arity contract rewritten to the contributors actually present in
-        # this window; retired contributors act through the group seed
-        counts = np.bincount(jid_w[jid_w >= 0], minlength=max(n_groups, 1))
-        jar_w = np.zeros(n_pad, np.int32)
-        wm = jwait_w >= 0
-        jar_w[wm] = counts[jwait_w[wm]].astype(np.int32)
-        del ca
-        seed = np.zeros(n_pad, np.int64)
-        for key, v in state.jseed.items():
-            seed[keys[key]] = v
+            for r in carried:
+                for key in (r["jwait"], r["jid"]):
+                    if key is not None:
+                        keys.setdefault(key, len(keys))
+            cj = _np(ck_hops.join_id)
+            cw = _np(ck_hops.join_wait)
+            ca = _np(ck_hops.join_arity)
+            for g in np.unique(np.concatenate([cj[cj >= 0], cw[cw >= 0]])):
+                keys.setdefault((ci, int(g)), len(keys))
+        n_groups = len(keys)
 
-    hops_w = Hops(
-        channel=jnp.asarray(W["channel"]),
-        nbytes=jnp.asarray(W["nbytes"]),
-        direction=jnp.asarray(W["direction"]),
-        row=jnp.asarray(W["row"]),
-        fixed_after_ps=jnp.asarray(W["fixed_after_ps"]),
-        is_payload=jnp.asarray(W["is_payload"]),
-        valid=jnp.asarray(W["valid"]),
-        extra_wire_bytes=(jnp.asarray(W["extra_wire_bytes"])
-                          if has_extra else None),
-        retrain_after_ps=(jnp.asarray(W["retrain_after_ps"])
-                          if has_retrain else None),
-        join_id=jnp.asarray(jid_w) if has_join else None,
-        join_wait=jnp.asarray(jwait_w) if has_join else None,
-        join_arity=jnp.asarray(jar_w) if has_join else None,
-    )
-    # copies, not views: jnp.asarray can alias host numpy buffers, and the
-    # async _stall_replay below would otherwise race the in-place frontier
-    # update at the end of this window
-    carry = StreamCarry(
-        depart_ps=jnp.asarray(state.ch_dep.copy()),
-        last_dir=jnp.asarray(state.ch_dir.copy()),
-        last_row=jnp.asarray(state.ch_row.copy()),
-        down_until_ps=jnp.asarray(state.ch_down.copy()),
-        join_seed_ps=jnp.asarray(seed) if has_join else None,
-    )
+        n_pad = -(-max(n_raw, n_groups, 1) // pad_to) * pad_to
+        h_w = max([h_c, 1] + [r["hops"]["channel"].shape[0] for r in carried])
 
-    # ---- resolve the window from the carried frontier
-    sched = simulate(hops_w, channels, jnp.asarray(issue_w), opts,
-                     carry=carry)
-    if bool(sched.converged):
-        arr = np.asarray(sched.arrive)
-        st = np.asarray(sched.start)
-        dp = np.asarray(sched.depart)
-        fold_sched = sched
-    else:
-        if not oracle_fallback:
-            raise RuntimeError(
-                f"window {state.windows} did not converge in "
-                f"{opts.max_rounds or round_bound(hops_w)} rounds "
-                "(check='off' disables the oracle fallback)")
-        ref = ref_des.simulate_ref(hops_w, channels, issue_w, carry=carry)
-        arr, st, dp = ref["arrive"], ref["start"], ref["depart"]
-        fold_sched = ref_des.ref_schedule(ref)
-        state.oracle_windows += 1
-    r_used = int(sched.rounds)
-    state.rounds_sum += r_used
-    state.rounds_max = max(state.rounds_max, r_used)
-    state.windows_converged += int(bool(sched.converged))
-
-    # ---- settlement: arrival <= T_next is final (see module docstring)
-    valid_np = W["valid"]
-    arr_h = arr[:, :h_w]
-    settled = arr_h <= t_next
-    real = gid_w >= 0
-    uns = valid_np & ~settled
-    anyu = uns.any(axis=1)
-    k0 = np.where(anyu, uns.argmax(axis=1), h_w)
-    if has_join:
-        hold = (jwait_w >= 0) & (arr[:, 0] > t_next) & real
-        k0 = np.where(hold, 0, k0)
-    else:
-        hold = np.zeros(n_pad, bool)
-    carried_mask = real & (anyu | hold)
-    retired = real & ~carried_mask
-
-    # ---- fold settled items / retired rows into the running telemetry
-    # gated arrival (hence the row's join wait) is final once the row
-    # retires or makes progress — each global row is recorded exactly once
-    gate_rec = (real & (hop0_w == 0)
-                & (retired | (carried_mask & (k0 > 0))))
-    lat = np.where(retired, arr[:, h_w] - orig_issue, 0)
-    gate_wait = np.where(gate_rec, arr[:, 0] - orig_issue, 0)
-    if has_retrain:
-        stall = _stall_replay(hops_w, channels, fold_sched, carry)
-    else:
-        stall = jnp.zeros((n_pad, h_w), jnp.int64)
-    state.telemetry = stream_telemetry_fold(
-        state.telemetry, hops_w, channels, fold_sched,
-        jnp.asarray(valid_np & settled), jnp.asarray(retired),
-        jnp.asarray(lat), stall, jnp.asarray(gate_rec),
-        jnp.asarray(gate_wait))
-
-    if collect is not None:
-        si, sh = np.nonzero((valid_np & settled) & real[:, None])
-        collect["item_row"].append(gid_w[si])
-        collect["item_hop"].append(hop0_w[si] + sh)
-        collect["item_start"].append(st[si, sh])
-        collect["item_depart"].append(dp[si, sh])
-        collect["item_arrive"].append(arr[si, sh])
-        rr = np.nonzero(retired)[0]
-        collect["row_id"].append(gid_w[rr])
-        collect["row_complete"].append(arr[rr, h_w])
-        rec = np.nonzero(gate_rec)[0]
-        collect["gate_row"].append(gid_w[rec])
-        collect["gate_arrive0"].append(arr[rec, 0])
-
-    # ---- advance the per-channel frontier past this window's settled prefix
-    serving = valid_np & (W["nbytes"] > 0)
-    ssi = serving & settled
-    ri, hi = np.nonzero(ssi)
-    if ri.size:
-        chs = W["channel"][ri, hi].astype(np.int64)
-        ars = arr_h[ri, hi]
-        fls = ri * h_w + hi
-        order = np.lexsort((fls, ars, chs))
-        sc = chs[order]
-        lastm = np.append(sc[1:] != sc[:-1], True)
-        sel = order[lastm]
-        lc = sc[lastm]
-        state.ch_dep[lc] = dp[ri[sel], hi[sel]]
-        state.ch_dir[lc] = W["direction"][ri[sel], hi[sel]]
-        rows = W["row"][ri, hi]
-        rm = rows >= 0
-        if rm.any():
-            order2 = np.lexsort((fls[rm], ars[rm], chs[rm]))
-            sc2 = chs[rm][order2]
-            lastm2 = np.append(sc2[1:] != sc2[:-1], True)
-            state.ch_row[sc2[lastm2]] = rows[rm][order2[lastm2]]
-    if has_retrain:
-        ret = W["retrain_after_ps"]
-        m1 = ssi & (ret > 0)
-        if m1.any():
-            np.maximum.at(state.ch_down, W["channel"][m1], dp[m1] + ret[m1])
-        mk = valid_np & (W["nbytes"] == 0) & (ret > 0) & settled
-        if mk.any():
-            np.maximum.at(state.ch_down, W["channel"][mk],
-                          arr_h[mk] + ret[mk])
-
-    # ---- streamed peak backlog: settled serving items emit +1 at arrival,
-    # −1 at grant; events strictly before T_next are flushed into the
-    # per-channel running fold (every future event is >= T_next: carried
-    # items arrive after it, new chunks issue at or after it), events at or
-    # after T_next stay pending so later same-instant arrivals keep the
-    # monolithic (time, arrivals-first) order
-    ev_t = np.concatenate([state.bl_t, arr_h[ri, hi], st[ri, hi]])
-    bc = W["channel"][ri, hi].astype(np.int64)
-    ev_c = np.concatenate([state.bl_c, bc, bc])
-    ev_y = np.concatenate([state.bl_y, np.zeros(ri.size, np.int8),
-                           np.ones(ri.size, np.int8)])
-    fl = ev_t < t_next
-    if fl.any():
-        _fold_backlog(state.bl_run, state.bl_peak,
-                      ev_t[fl], ev_c[fl], ev_y[fl])
-    keep = ~fl
-    state.bl_t, state.bl_c, state.bl_y = ev_t[keep], ev_c[keep], ev_y[keep]
-
-    # ---- extract the rows still in flight as next-window suffixes
-    inv = {v: k for k, v in keys.items()} if has_join else {}
-    new_carried = []
-    for p in np.nonzero(carried_mask)[0]:
-        k = int(k0[p])
-        vrow = valid_np[p]
-        top = max((h_w - int(vrow[::-1].argmax())) if vrow.any() else 0, k)
-        jw = jd = None
+        # ---- assemble the window: carried suffixes, chunk rows, padding
+        W = {
+            "channel": np.zeros((n_pad, h_w), np.int32),
+            "nbytes": np.zeros((n_pad, h_w), np.int64),
+            "direction": np.zeros((n_pad, h_w), np.int8),
+            "row": np.full((n_pad, h_w), -1, np.int32),
+            "fixed_after_ps": np.zeros((n_pad, h_w), np.int64),
+            "is_payload": np.zeros((n_pad, h_w), bool),
+            "valid": np.zeros((n_pad, h_w), bool),
+        }
+        if has_extra:
+            W["extra_wire_bytes"] = np.zeros((n_pad, h_w), np.int64)
+        if has_retrain:
+            W["retrain_after_ps"] = np.zeros((n_pad, h_w), np.int64)
+        issue_w = np.zeros(n_pad, np.int64)
+        orig_issue = np.zeros(n_pad, np.int64)
+        gid_w = np.full(n_pad, -1, np.int64)
+        hop0_w = np.zeros(n_pad, np.int64)
         if has_join:
-            if hold[p]:
-                jw = inv[int(jwait_w[p])]
-            if jid_w[p] >= 0:
-                jd = inv[int(jid_w[p])]
-        new_carried.append(dict(
-            hops={f: W[f][p, k:top].copy() for f in W},
-            issue=int(issue_w[p]) if k == 0 else int(arr[p, k]),
-            orig_issue=int(orig_issue[p]),
-            gid=int(gid_w[p]),
-            hop0=int(hop0_w[p]) + k,
-            jwait=jw, jid=jd,
-        ))
+            jid_w = np.full(n_pad, -1, np.int32)
+            jwait_w = np.full(n_pad, -1, np.int32)
 
-    # retired contributors of still-gated groups act through the seed;
-    # groups whose every waiter retired are dead — drop their entries
-    alive = {r["jwait"] for r in new_carried if r["jwait"] is not None}
-    new_seed = {k: v for k, v in state.jseed.items() if k in alive}
-    if has_join and alive:
-        for p in np.nonzero(retired & (jid_w >= 0))[0]:
-            key = inv[int(jid_w[p])]
-            if key in alive:
-                new_seed[key] = max(new_seed.get(key, 0), int(arr[p, h_w]))
-    state.jseed = new_seed
+        for i, r in enumerate(carried):
+            length = r["hops"]["channel"].shape[0]
+            for f, a in r["hops"].items():
+                W[f][i, :length] = a
+            issue_w[i] = r["issue"]
+            orig_issue[i] = r["orig_issue"]
+            gid_w[i] = r["gid"]
+            hop0_w[i] = r["hop0"]
+            if has_join:
+                if r["jid"] is not None:
+                    jid_w[i] = keys[r["jid"]]
+                if r["jwait"] is not None:
+                    jwait_w[i] = keys[r["jwait"]]
+        for f in W:
+            W[f][n_k:n_raw, :h_c] = c_np[f]
+        issue_w[n_k:n_raw] = c_issue
+        orig_issue[n_k:n_raw] = c_issue
+        gid_w[n_k:n_raw] = state.gid_next + np.arange(n_c)
+        state.gid_next += n_c
+        if has_join:
+            for src, dst in ((cj, jid_w), (cw, jwait_w)):
+                m = src >= 0
+                dst[n_k:n_raw][m] = np.fromiter(
+                    (keys[(ci, int(g))] for g in src[m]), np.int32, int(m.sum()))
+            # arity contract rewritten to the contributors actually present in
+            # this window; retired contributors act through the group seed
+            counts = np.bincount(jid_w[jid_w >= 0], minlength=max(n_groups, 1))
+            jar_w = np.zeros(n_pad, np.int32)
+            wm = jwait_w >= 0
+            jar_w[wm] = counts[jwait_w[wm]].astype(np.int32)
+            del ca
+            seed = np.zeros(n_pad, np.int64)
+            for key, v in state.jseed.items():
+                seed[keys[key]] = v
 
-    state.carried = new_carried
-    state.carried_peak = max(state.carried_peak, len(new_carried))
-    state.windows += 1
-    state.n_rows += n_c
-    state.chunk_idx += 1
+        hops_w = Hops(
+            channel=jnp.asarray(W["channel"]),
+            nbytes=jnp.asarray(W["nbytes"]),
+            direction=jnp.asarray(W["direction"]),
+            row=jnp.asarray(W["row"]),
+            fixed_after_ps=jnp.asarray(W["fixed_after_ps"]),
+            is_payload=jnp.asarray(W["is_payload"]),
+            valid=jnp.asarray(W["valid"]),
+            extra_wire_bytes=(jnp.asarray(W["extra_wire_bytes"])
+                              if has_extra else None),
+            retrain_after_ps=(jnp.asarray(W["retrain_after_ps"])
+                              if has_retrain else None),
+            join_id=jnp.asarray(jid_w) if has_join else None,
+            join_wait=jnp.asarray(jwait_w) if has_join else None,
+            join_arity=jnp.asarray(jar_w) if has_join else None,
+        )
+        # copies, not views: jnp.asarray can alias host numpy buffers, and the
+        # async _stall_replay below would otherwise see the in-place frontier
+        # update of this window's settlement, which runs before it
+        carry = StreamCarry(
+            depart_ps=jnp.asarray(state.ch_dep.copy()),
+            last_dir=jnp.asarray(state.ch_dir.copy()),
+            last_row=jnp.asarray(state.ch_row.copy()),
+            down_until_ps=jnp.asarray(state.ch_down.copy()),
+            join_seed_ps=jnp.asarray(seed) if has_join else None,
+        )
+
+    with span("window.resolve"):
+        # ---- resolve the window from the carried frontier
+        sched = simulate(hops_w, channels, jnp.asarray(issue_w), opts,
+                         carry=carry)
+        if bool(sched.converged):
+            arr = np.asarray(sched.arrive)
+            st = np.asarray(sched.start)
+            dp = np.asarray(sched.depart)
+            fold_sched = sched
+        else:
+            if not oracle_fallback:
+                raise RuntimeError(
+                    f"window {state.windows} did not converge in "
+                    f"{opts.max_rounds or round_bound(hops_w)} rounds "
+                    "(check='off' disables the oracle fallback)")
+            ref = ref_des.simulate_ref(hops_w, channels, issue_w, carry=carry)
+            arr, st, dp = ref["arrive"], ref["start"], ref["depart"]
+            fold_sched = ref_des.ref_schedule(ref)
+            state.oracle_windows += 1
+        r_used = int(sched.rounds)
+        state.rounds_sum += r_used
+        state.rounds_max = max(state.rounds_max, r_used)
+        state.windows_converged += int(bool(sched.converged))
+
+    with span("window.settle"):
+        # ---- settlement: arrival <= T_next is final (see module docstring)
+        valid_np = W["valid"]
+        arr_h = arr[:, :h_w]
+        settled = arr_h <= t_next
+        real = gid_w >= 0
+        uns = valid_np & ~settled
+        anyu = uns.any(axis=1)
+        k0 = np.where(anyu, uns.argmax(axis=1), h_w)
+        if has_join:
+            hold = (jwait_w >= 0) & (arr[:, 0] > t_next) & real
+            k0 = np.where(hold, 0, k0)
+        else:
+            hold = np.zeros(n_pad, bool)
+        carried_mask = real & (anyu | hold)
+        retired = real & ~carried_mask
+
+        # ---- advance the per-channel frontier past this window's settled prefix
+        serving = valid_np & (W["nbytes"] > 0)
+        ssi = serving & settled
+        ri, hi = np.nonzero(ssi)
+        if ri.size:
+            chs = W["channel"][ri, hi].astype(np.int64)
+            ars = arr_h[ri, hi]
+            fls = ri * h_w + hi
+            order = np.lexsort((fls, ars, chs))
+            sc = chs[order]
+            lastm = np.append(sc[1:] != sc[:-1], True)
+            sel = order[lastm]
+            lc = sc[lastm]
+            state.ch_dep[lc] = dp[ri[sel], hi[sel]]
+            state.ch_dir[lc] = W["direction"][ri[sel], hi[sel]]
+            rows = W["row"][ri, hi]
+            rm = rows >= 0
+            if rm.any():
+                order2 = np.lexsort((fls[rm], ars[rm], chs[rm]))
+                sc2 = chs[rm][order2]
+                lastm2 = np.append(sc2[1:] != sc2[:-1], True)
+                state.ch_row[sc2[lastm2]] = rows[rm][order2[lastm2]]
+        if has_retrain:
+            ret = W["retrain_after_ps"]
+            m1 = ssi & (ret > 0)
+            if m1.any():
+                np.maximum.at(state.ch_down, W["channel"][m1], dp[m1] + ret[m1])
+            mk = valid_np & (W["nbytes"] == 0) & (ret > 0) & settled
+            if mk.any():
+                np.maximum.at(state.ch_down, W["channel"][mk],
+                              arr_h[mk] + ret[mk])
+
+    with span("window.fold"):
+        # ---- fold settled items / retired rows into the running telemetry,
+        # dispatched and not awaited: the device folds while the host merges
+        # the backlog below.  A gated arrival (hence the row's join wait) is
+        # final once the row retires or makes progress — each global row is
+        # recorded exactly once
+        gate_rec = (real & (hop0_w == 0)
+                    & (retired | (carried_mask & (k0 > 0))))
+        lat = np.where(retired, arr[:, h_w] - orig_issue, 0)
+        gate_wait = np.where(gate_rec, arr[:, 0] - orig_issue, 0)
+        if has_retrain:
+            stall = _stall_replay(hops_w, channels, fold_sched, carry)
+        else:
+            stall = jnp.zeros((n_pad, h_w), jnp.int64)
+        state.telemetry = stream_telemetry_fold(
+            state.telemetry, hops_w, channels, fold_sched,
+            jnp.asarray(valid_np & settled), jnp.asarray(retired),
+            jnp.asarray(lat), stall, jnp.asarray(gate_rec),
+            jnp.asarray(gate_wait))
+
+        if collect is not None:
+            si, sh = np.nonzero((valid_np & settled) & real[:, None])
+            collect["item_row"].append(gid_w[si])
+            collect["item_hop"].append(hop0_w[si] + sh)
+            collect["item_start"].append(st[si, sh])
+            collect["item_depart"].append(dp[si, sh])
+            collect["item_arrive"].append(arr[si, sh])
+            rr = np.nonzero(retired)[0]
+            collect["row_id"].append(gid_w[rr])
+            collect["row_complete"].append(arr[rr, h_w])
+            rec = np.nonzero(gate_rec)[0]
+            collect["gate_row"].append(gid_w[rec])
+            collect["gate_arrive0"].append(arr[rec, 0])
+
+    with span("window.backlog"):
+        # ---- streamed peak backlog: settled serving items emit +1 at arrival,
+        # −1 at grant; events strictly before T_next are flushed into the
+        # per-channel running fold (every future event is >= T_next: carried
+        # items arrive after it, new chunks issue at or after it), events at or
+        # after T_next stay pending so later same-instant arrivals keep the
+        # monolithic (time, arrivals-first) order
+        ev_t = np.concatenate([state.bl_t, arr_h[ri, hi], st[ri, hi]])
+        bc = W["channel"][ri, hi].astype(np.int64)
+        ev_c = np.concatenate([state.bl_c, bc, bc])
+        ev_y = np.concatenate([state.bl_y, np.zeros(ri.size, np.int8),
+                               np.ones(ri.size, np.int8)])
+        fl = ev_t < t_next
+        if fl.any():
+            _fold_backlog(state.bl_run, state.bl_peak,
+                          ev_t[fl], ev_c[fl], ev_y[fl])
+        keep = ~fl
+        state.bl_t, state.bl_c, state.bl_y = ev_t[keep], ev_c[keep], ev_y[keep]
+
+    with span("window.carry"):
+        # ---- extract the rows still in flight as next-window suffixes
+        inv = {v: k for k, v in keys.items()} if has_join else {}
+        new_carried = []
+        for p in np.nonzero(carried_mask)[0]:
+            k = int(k0[p])
+            vrow = valid_np[p]
+            top = max((h_w - int(vrow[::-1].argmax())) if vrow.any() else 0, k)
+            jw = jd = None
+            if has_join:
+                if hold[p]:
+                    jw = inv[int(jwait_w[p])]
+                if jid_w[p] >= 0:
+                    jd = inv[int(jid_w[p])]
+            new_carried.append(dict(
+                hops={f: W[f][p, k:top].copy() for f in W},
+                issue=int(issue_w[p]) if k == 0 else int(arr[p, k]),
+                orig_issue=int(orig_issue[p]),
+                gid=int(gid_w[p]),
+                hop0=int(hop0_w[p]) + k,
+                jwait=jw, jid=jd,
+            ))
+
+        # retired contributors of still-gated groups act through the seed;
+        # groups whose every waiter retired are dead — drop their entries
+        alive = {r["jwait"] for r in new_carried if r["jwait"] is not None}
+        new_seed = {k: v for k, v in state.jseed.items() if k in alive}
+        if has_join and alive:
+            for p in np.nonzero(retired & (jid_w >= 0))[0]:
+                key = inv[int(jid_w[p])]
+                if key in alive:
+                    new_seed[key] = max(new_seed.get(key, 0), int(arr[p, h_w]))
+        state.jseed = new_seed
+
+        state.carried = new_carried
+        state.carried_peak = max(state.carried_peak, len(new_carried))
+        state.windows += 1
+        state.n_rows += n_c
+        state.chunk_idx += 1
 
 
 def simulate_stream(chunks, channels: Channels, state: StreamState = None,
