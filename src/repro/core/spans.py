@@ -17,7 +17,7 @@ Device scopes (`jax.named_scope`, in the ``op_name`` metadata of the
 compiled program's operations): the steps of `engine._one_round`, the sort
 order (``round.order``), the gathers into sorted order and of the carried
 seeds (``round.gather``), the serve scan or kernel with its fallback
-(``round.serve``), and the scatter with the arrival propagation
+(``round.serve``), and the un-permuting sort with the arrival propagation
 (``round.scatter``).
 
 Nothing is recorded unless a profiler trace is running; a scope changes

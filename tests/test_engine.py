@@ -7,8 +7,11 @@ from _hyp_compat import given, settings, st  # optional-hypothesis shim
 import jax.numpy as jnp
 
 import repro.core  # noqa: F401
+from repro.core import engine
 from repro.core.engine import (Channels, Hops, SimOptions, channel_stats,
-                               request_stats, simulate, simulate_auto)
+                               empty_carry, hop_ser_ps, replay_round,
+                               request_stats, round_bound, simulate,
+                               simulate_auto)
 from repro.core.ref_des import simulate_ref
 
 
@@ -266,3 +269,82 @@ def test_latency_positive_and_fcfs_order():
     st_ = np.asarray(sched.start)[valid]
     ar = np.asarray(sched.arrive)[:, :-1][valid]
     assert (st_ >= ar).all()
+
+
+# ---------------------------------------------------------------------------
+# the round un-permutes its sorted outputs by a sort on their order
+# ---------------------------------------------------------------------------
+
+def _scatter_unpermute(order, *xs):
+    """The scatters through ``order`` that `engine._unpermute` replaced."""
+    k = order.shape[0]
+    return tuple(jnp.zeros(k, jnp.int64).at[order].set(x) for x in xs)
+
+
+def _retrain_case(seed):
+    """`_random_case` with sampled retraining stalls on a fifth of the hops
+    (no link-down markers: zero-byte hops carry none)."""
+    hops, ch, issue, valid = _random_case(seed)
+    rng = np.random.default_rng(seed + 1)
+    retrain = np.where(rng.random(hops.channel.shape) < .2,
+                       rng.integers(1, 4, hops.channel.shape) * 100_000, 0)
+    retrain = np.where(np.asarray(hops.nbytes) == 0, 0, retrain)
+    return hops._replace(retrain_after_ps=jnp.asarray(retrain, jnp.int64)), \
+        ch, issue, valid
+
+
+@pytest.mark.parametrize("case", [
+    "helper-2", "helper-3",
+    "fixpoint-scan", "fixpoint-scan-carry", "fixpoint-ref",
+    "fixpoint-ref-carry",
+    "replay-retrain",
+])
+def test_round_unpermutes_by_sort(case, monkeypatch):
+    if case.startswith("helper"):
+        # equal to the scatter for random permutations, int64 limits included
+        n_arrays = int(case[-1])
+        rng = np.random.default_rng(n_arrays)
+        lo, hi = np.iinfo(np.int64).min, np.iinfo(np.int64).max
+        for k in (1, 7, 4099):
+            order = jnp.asarray(rng.permutation(k).astype(np.int32))
+            xs = []
+            for _ in range(n_arrays):
+                x = rng.integers(lo, hi, k, dtype=np.int64, endpoint=True)
+                x[rng.integers(0, k, 2)] = (lo, hi)
+                x[rng.integers(0, k)] = hi - 1
+                xs.append(jnp.asarray(x))
+            got = engine._unpermute(order, *xs)
+            want = _scatter_unpermute(order, *xs)
+            assert len(got) == n_arrays
+            for g, w in zip(got, want):
+                assert g.dtype == jnp.int64
+                assert np.array_equal(np.asarray(g), np.asarray(w))
+    elif case.startswith("fixpoint"):
+        # the fixpoint program, on both serve paths, still equals the oracle
+        impl = case.split("-")[1]
+        for seed in (5, 23):
+            hops, ch, issue, valid = _random_case(seed)
+            carry = (empty_carry(int(ch.bw_MBps.shape[0]))
+                     if case.endswith("carry") else None)
+            sched = engine._simulate_fixpoint(
+                hops, ch, jnp.asarray(issue), hop_ser_ps(hops, ch),
+                jnp.int64(round_bound(hops)), carry, impl=impl)
+            ref = simulate_ref(hops, ch, issue)
+            assert bool(sched.converged)
+            assert np.array_equal(np.asarray(sched.complete), ref["complete"])
+            for f in ("start", "depart"):
+                assert np.array_equal(np.asarray(getattr(sched, f))[valid],
+                                      ref[f][valid]), f
+    else:
+        # the telemetry replay on a retrain layout: the schedule reproduced,
+        # and the stall table the scatters gave
+        hops, ch, issue, _ = _retrain_case(9)
+        sched = simulate(hops, ch, jnp.asarray(issue))
+        assert bool(sched.converged)
+        start, depart, stall = replay_round(hops, ch, sched)
+        assert np.array_equal(np.asarray(start), np.asarray(sched.start))
+        assert np.array_equal(np.asarray(depart), np.asarray(sched.depart))
+        monkeypatch.setattr(engine, "_unpermute", _scatter_unpermute)
+        _, _, want = replay_round(hops, ch, sched)
+        assert int(jnp.sum(want)) > 0
+        assert np.array_equal(np.asarray(stall), np.asarray(want))
